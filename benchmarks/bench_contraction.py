@@ -4,7 +4,7 @@ Compares the three ways this repo expresses the sum-factorization
 contractions (the axhelm hot loop):
 
   einsum    — jnp.einsum per axis (the reference path, core/sumfact.py)
-  matmul    — explicit reshape-to-matmul (the Pallas kernel's MXU shapes)
+  matmul    — lane-dense Kronecker matmuls (the Pallas kernel's MXU shapes)
   fused     — one jitted function doing grad + factors + grad^T (what the
               kernel fuses in VMEM)
 
@@ -23,7 +23,7 @@ import numpy as np
 
 from repro.core import geometry, mesh_gen, sumfact
 from repro.core.spectral import basis
-from repro.kernels.axhelm.kernel import _grad, _grad_transpose
+from repro.kernels.axhelm.kernel import _grad, kernel_constants
 
 
 def _time(fn, *args, iters: int = 10) -> float:
@@ -46,7 +46,9 @@ def rows(n: int = 7, e: int = 512):
     factors = geometry.factors_trilinear(verts, b)
 
     einsum_fn = jax.jit(lambda xx: sumfact.grad_ref(xx, dhat))
-    matmul_fn = jax.jit(lambda xx: _grad(xx, dhat))
+    krs, kt = kernel_constants("precomputed", b, jnp.float32)[:2]
+    matmul_fn = jax.jit(lambda xx: tuple(
+        g.reshape(xx.shape) for g in _grad(xx.reshape(e, -1), krs, kt)))
 
     def fused(xx):
         xr, xs, xt = sumfact.grad_ref(xx, dhat)
@@ -83,4 +85,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+
+    compile_cache.enable()
     main()

@@ -23,9 +23,11 @@ exchanges.  Results land in BENCH_nekbone.json:
 
     {"table6": [...], "scaling": [...], "multirhs": [...], "surface": [...]}
 
-Device counts beyond the visible devices are simulated by re-running this
-script in a subprocess with --xla_force_host_platform_device_count (the
-parent process must keep its 1-device backend).
+On the CPU, device counts beyond the visible devices are simulated by
+re-running this script in a subprocess with JAX_PLATFORMS=cpu and
+--xla_force_host_platform_device_count (the parent process must keep its
+1-device backend).  On an accelerator every row runs in this process,
+which holds the chips: there the sharded rows need that many devices.
 """
 
 from __future__ import annotations
@@ -448,8 +450,20 @@ def _check_scaling(sc):
 
 
 def _child_rows(child_flag, forced_devices, *extra_args):
-    """Re-run this file with forced host devices; collect its JSON rows."""
+    """Re-run this file on `forced_devices` simulated host devices; collect
+    its JSON rows.
+
+    CPU only: this process already holds its backend, so a child that
+    needed an accelerator would find it taken.  On a chip the rows run in
+    this process, which then needs that many devices.
+    """
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"these rows need {forced_devices} devices but "
+            f"{jax.device_count()} {jax.default_backend()} device(s) are "
+            f"visible; simulated host devices exist on the CPU only")
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count="
                           f"{forced_devices}")
@@ -603,4 +617,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+
+    compile_cache.enable()
     main()

@@ -104,6 +104,9 @@ def main():
     import jax.numpy as jnp
     import numpy as np
 
+    from repro import compile_cache
+
+    compile_cache.enable()
     block_elems = args.block_elems
     if block_elems is not None and block_elems != "auto":
         block_elems = int(block_elems)

@@ -391,7 +391,7 @@ class NoHostTransfer(Contract):
 class VmemBudget(Contract):
     """The Pallas block configuration fits the autotuner's VMEM model
     (`kernels.axhelm.tune.block_vmem_bytes` vs `VMEM_BUDGET_BYTES`) —
-    the enforcement point of the v2 model in kernels/axhelm/DESIGN.md."""
+    the enforcement point of the v3 model in kernels/axhelm/DESIGN.md."""
 
     name = "vmem-budget"
 

@@ -326,7 +326,7 @@ def _axhelm_checks(variant: str) -> List[Check]:
 for _variant in ("precomputed", "trilinear", "parallelepiped", "merged",
                  "partial"):
     entry(f"axhelm_{_variant}",
-          f"axhelm[{_variant}]: dispatched block fits the v2 VMEM model; "
+          f"axhelm[{_variant}]: dispatched block fits the v3 VMEM model; "
           f"bf16 reference op accumulates in fp32")(
         lambda v=_variant: _axhelm_checks(v))
 

@@ -178,9 +178,10 @@ def element_diagonal(factors: GeomFactors, dhat: jnp.ndarray,
         g = g * lam0[..., None]
     d2 = dhat * dhat
     dd = jnp.diagonal(dhat)
-    diag = jnp.einsum("mi,...m->...i", d2, g[..., 0])
-    diag = diag + jnp.einsum("mj,...mi->...ji", d2, g[..., 3])
-    diag = diag + jnp.einsum("mk,...mji->...kji", d2, g[..., 5])
+    hi = sumfact.HIGHEST
+    diag = jnp.einsum("mi,...m->...i", d2, g[..., 0], precision=hi)
+    diag = diag + jnp.einsum("mj,...mi->...ji", d2, g[..., 3], precision=hi)
+    diag = diag + jnp.einsum("mk,...mji->...kji", d2, g[..., 5], precision=hi)
     di = dd[None, None, :]
     dj = dd[None, :, None]
     dk = dd[:, None, None]
@@ -202,22 +203,19 @@ class AxhelmOp(NamedTuple):
 
 
 BACKENDS = ("reference", "pallas", "auto")
-BACKEND_ENV = "REPRO_AXHELM_BACKEND"
 
 
 def _resolve_backend(backend: Optional[str], dtype) -> str:
-    """Map backend choice (or the REPRO_AXHELM_BACKEND env default) to a
-    concrete implementation.
+    """Map a backend choice (None means "reference") to a concrete
+    implementation.
 
     "auto" picks the Pallas kernels whenever the dtype fits the MXU (fp32 /
     bf16 — the kernels accumulate in fp32; off-TPU they run in interpret
     mode so CPU CI exercises the same code path) and falls back to the
     pure-jnp reference for fp64, which the TPU MXU cannot compute anyway.
     """
-    import os
-
     if backend is None:
-        backend = os.environ.get(BACKEND_ENV, "reference")
+        backend = "reference"
     if backend not in BACKENDS:
         raise ValueError(f"unknown axhelm backend {backend!r}; "
                          f"expected one of {BACKENDS}")
@@ -243,7 +241,9 @@ def _pallas_operands(variant: str, basis: SpectralBasis, verts, factors,
     l1 = _node_field(lam1, dtype, node_shape)
 
     if variant == "precomputed":
-        geom = jnp.concatenate([factors.g, factors.gwj[..., None]], axis=-1)
+        # planar (E, 7, N1, N1, N1): one lane-dense plane per factor
+        geom = jnp.concatenate([jnp.moveaxis(factors.g, -1, 1),
+                                factors.gwj[:, None]], axis=1)
     elif variant == "parallelepiped":
         from repro.kernels.axhelm.ref import gelem_from_verts
         geom = gelem_from_verts(verts)
@@ -299,8 +299,8 @@ def _setup_factors(variant: str, basis: SpectralBasis, verts, coords,
     if variant == "precomputed":
         if "g" in elem_ops:                      # reference operands
             return GeomFactors(elem_ops["g"], elem_ops["gwj"])
-        if "geom" in elem_ops:                   # pallas packed [g6, gwj]
-            geom = elem_ops["geom"]
+        if "geom" in elem_ops:                   # pallas planar [g6, gwj]
+            geom = jnp.moveaxis(elem_ops["geom"], 1, -1)
             return GeomFactors(geom[..., :6], geom[..., 6])
         if coords is None:
             coords = geometry.node_coords(verts, basis)
@@ -333,7 +333,7 @@ def make_axhelm(variant: str, basis: SpectralBasis, verts: jnp.ndarray,
     `backend` selects the element-kernel implementation: "reference" (pure
     jnp, any dtype), "pallas" (the TPU kernels in repro.kernels.axhelm;
     interpret mode off-TPU), or "auto" (pallas for fp32/bf16, reference for
-    fp64).  Default: the REPRO_AXHELM_BACKEND env var, else "reference".
+    fp64).  Default: "reference".
     `block_elems`/`interpret` are forwarded to the Pallas path (see
     kernels/axhelm/ops.axhelm; block_elems="auto" invokes the autotuner).
     """
@@ -404,6 +404,10 @@ def make_axhelm_elem_ops(variant: str, basis: SpectralBasis,
         kw = {} if variant in ("merged", "partial") else {
             "helmholtz": helmholtz}
         from repro.kernels.axhelm import ops as kops
+
+        # decided at setup: a compiled kernel asked for off a TPU fails
+        # here, not at the first apply inside a solve
+        interpret = kops.resolve_interpret(interpret)
 
         def apply(x, elem_ops):
             return kops.axhelm(x, basis, variant, elem_ops["geom"],

@@ -24,12 +24,11 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import axhelm as axhelm_mod
 from repro.core import gather_scatter as gs
 from repro.core import geometry
-from repro.distributed.context import shard_map_compat
 from repro.core.mesh_gen import BoxMesh, MeshPartition, partition_elements
 from repro.core.pcg import PCGResult, owned_dot, pcg, pcg_block, refine
 from repro.core.spectral import SpectralBasis, basis as make_basis
@@ -85,6 +84,7 @@ class ShardedNekboneProblem(NamedTuple):
     run_pcg: object              # (b, tol, max_iter, precond=) -> PCGResult
     precision: Optional[str] = None  # None (plain) or "bf16_x32"
     run_refined: object = None   # sharded fp32-outer/bf16-inner runner
+    shard_arrays: object = None  # per-shard operands, split over the mesh
 
 
 def _global_op(element_op, mesh: BoxMesh, mask):
@@ -393,14 +393,15 @@ def _setup_problem_sharded(mesh: BoxMesh, b: SpectralBasis, variant: str,
             variant, b, flat_verts.astype(lo), lam0=_cast_opt(lam_sh[0], lo),
             lam1=_cast_opt(lam_sh[1], lo), helmholtz=helmholtz, dtype=lo,
             backend=backend, block_elems=block_lo, interpret=interpret)
-    apply_global, run_pcg, run_refined = _build_sharded_runner(
+    apply_global, run_pcg, run_refined, arrays = _build_sharded_runner(
         part, shard_ctx, elem_ops, elem_apply, mask, diag, d,
         mesh.n_global, elem_ops_lo=elem_ops_lo,
         elem_apply_lo=elem_apply_lo,
         compress=getattr(shard_ctx, "compress", None))
     return ShardedNekboneProblem(apply_global, diag, mask, mesh, b, d,
                                  helmholtz, variant, backend_used, shard_ctx,
-                                 part, run_pcg, precision, run_refined)
+                                 part, run_pcg, precision, run_refined,
+                                 arrays)
 
 
 def _build_sharded_runner(part: MeshPartition, ctx, elem_ops, elem_apply,
@@ -429,21 +430,28 @@ def _build_sharded_runner(part: MeshPartition, ctx, elem_ops, elem_apply,
     full width (the outer residual is the safety net; compressing it
     would re-introduce the very floor the refinement removes).
 
-    Returns ``(apply_global, run_pcg, run_refined)`` — the last is None
-    without a lo operand set.
+    Every per-shard array (element operands, index sets, the local
+    diagonal and mask) is placed split over the mesh at setup and reaches
+    the jitted runners as an argument, never as a captured constant: each
+    device holds its own shard at rest, and a call moves only the vectors.
+
+    Returns ``(apply_global, run_pcg, run_refined, arrays)``: each runner
+    is its jitted function with `arrays` — the pytree of placed per-shard
+    operands — bound as the first argument (``.func`` lowers it alone);
+    `run_refined` is None without a lo operand set.
     """
     axis = ctx.axis
     s, ep, nl, ns = (part.n_shards, part.e_per_shard, part.n_local,
                      part.n_shared)
     n1 = part.local_ids.shape[-1]
-    local_ids = jnp.asarray(part.local_ids.reshape(s * ep, n1, n1, n1))
-    shared_idx = jnp.asarray(part.shared_idx.reshape(-1))
-    present = jnp.asarray(part.shared_present.reshape(-1))
-    l2g = jnp.asarray(part.local_to_global.reshape(-1))
-    owned = jnp.asarray(part.owned_mask.reshape(-1))
-    valid = jnp.asarray(part.valid_mask.reshape(-1))
-    diag_loc = diag[l2g]
-    mask_loc = mask[l2g] if mask is not None else jnp.zeros(s * nl, bool)
+    pe = P(axis)
+    split_over_mesh = NamedSharding(ctx.mesh, pe)
+
+    def place(tree):
+        return jax.device_put(tree, split_over_mesh)
+
+    l2g = part.local_to_global.reshape(-1)
+    mask_loc = mask[l2g] if mask is not None else np.zeros(s * nl, bool)
     has_mask = mask is not None
     neighbour = getattr(ctx, "exchange", "psum") == "neighbour"
     # static interface/interior launch plan (see _neighbour_launch_plan):
@@ -453,26 +461,30 @@ def _build_sharded_runner(part: MeshPartition, ctx, elem_ops, elem_apply,
     nbr_args = ()
     if neighbour:
         nbr_args = tuple(
-            jnp.asarray(t.reshape(-1))
+            t.reshape(-1)
             for j in range(len(part.nbr_offsets))
             for t in (part.nbr_lo_idx[j], part.nbr_lo_mask[j],
                       part.nbr_hi_idx[j], part.nbr_hi_mask[j]))
+    arrays = place(dict(
+        ops=elem_ops, ops_lo=elem_ops_lo, diag=diag[l2g], l2g=l2g,
+        idx=(part.local_ids.reshape(s * ep, n1, n1, n1),
+             part.shared_idx.reshape(-1), part.shared_present.reshape(-1),
+             part.owned_mask.reshape(-1), part.valid_mask.reshape(-1),
+             mask_loc) + nbr_args))
 
-    pe = P(axis)
     ops_specs = jax.tree.map(lambda _: pe, elem_ops)
-    idx_args = (local_ids, shared_idx, present, owned, valid,
-                mask_loc) + nbr_args
-    idx_specs = (pe,) * len(idx_args)
+    idx_specs = (pe,) * len(arrays["idx"])
     expand = gs._expand_mask
 
-    def localize(xg):
-        xl = xg[l2g]
-        return jnp.where(expand(valid, xl), xl, 0)
+    def localize(xg, a):
+        xl = xg[a["l2g"]]
+        return jnp.where(expand(a["idx"][4], xl), xl, 0)     # valid slots
 
-    def globalize(xl):
-        w = expand(owned, xl)
+    def globalize(xl, a):
+        w = expand(a["idx"][3], xl)                         # owned dofs
         shape = (n_global,) + xl.shape[1:]
-        return jnp.zeros(shape, xl.dtype).at[l2g].add(jnp.where(w, xl, 0))
+        return jnp.zeros(shape, xl.dtype).at[a["l2g"]].add(
+            jnp.where(w, xl, 0))
 
     def _make_a_op(apply_fn, wire):
         """The per-shard operator body for ONE element-kernel apply fn.
@@ -569,13 +581,17 @@ def _build_sharded_runner(part: MeshPartition, ctx, elem_ops, elem_apply,
     a_op_lo_local = (None if elem_apply_lo is None
                      else _make_a_op(elem_apply_lo, compress))
 
-    smap = functools.partial(shard_map_compat, mesh=ctx.mesh)
+    # the replication check is off: the bodies psum to replicated outputs,
+    # which the static varying-axes check cannot infer
+    smap = functools.partial(jax.shard_map, mesh=ctx.mesh, check_vma=False)
 
     @jax.jit
-    def apply_global(xg):
+    def _apply_global(a, xg):
         body = smap(a_op_local, in_specs=(pe, ops_specs) + idx_specs,
                     out_specs=pe)
-        return globalize(body(localize(xg), elem_ops, *idx_args))
+        return globalize(body(localize(xg, a), a["ops"], *a["idx"]), a)
+
+    apply_global = functools.partial(_apply_global, arrays)
 
     def pcg_body(b_loc, dg, tol, max_iter, x0_loc, eo, lid, sidx, spres, own,
                  val, m, *nbr, use_jacobi, batched, window, fault, fdof):
@@ -632,16 +648,16 @@ def _build_sharded_runner(part: MeshPartition, ctx, elem_ops, elem_apply,
     @functools.partial(jax.jit, static_argnames=("precond",
                                                  "stagnation_window",
                                                  "fault"))
-    def run_pcg(b_global, tol, max_iter, precond="jacobi", x0=None,
-                stagnation_window=0, fault=None):
+    def _run_pcg(a, b_global, tol, max_iter, precond="jacobi", x0=None,
+                 stagnation_window=0, fault=None):
         # trailing axes beyond the (Ng[, d]) base layout are the RHS batch
         batched = b_global.ndim > (2 if d > 1 else 1)
         fdof = _validate_fault(fault) if fault is not None else None
-        b_loc = localize(b_global)
+        b_loc = localize(b_global, a)
         # pcg treats a zero x0 identically to x0=None (the initial
         # residual applies A either way), so the restart path can always
         # thread an explicit iterate without a second trace shape
-        x0_loc = localize(x0) if x0 is not None else jnp.zeros_like(b_loc)
+        x0_loc = localize(x0, a) if x0 is not None else jnp.zeros_like(b_loc)
         body = smap(
             functools.partial(pcg_body, use_jacobi=precond == "jacobi",
                               batched=batched, window=stagnation_window,
@@ -649,10 +665,12 @@ def _build_sharded_runner(part: MeshPartition, ctx, elem_ops, elem_apply,
             in_specs=(pe, pe, P(), P(), pe, ops_specs) + idx_specs,
             out_specs=(pe, pe, pe, pe, pe, pe))
         x_loc, it, rr, r0, brk, st = body(
-            b_loc, diag_loc, jnp.asarray(tol),
-            jnp.asarray(max_iter, jnp.int32), x0_loc, elem_ops, *idx_args)
-        return PCGResult(globalize(x_loc), it[0], rr[0], r0[0], brk[0],
+            b_loc, a["diag"], jnp.asarray(tol),
+            jnp.asarray(max_iter, jnp.int32), x0_loc, a["ops"], *a["idx"])
+        return PCGResult(globalize(x_loc, a), it[0], rr[0], r0[0], brk[0],
                          st[0])
+
+    run_pcg = functools.partial(_run_pcg, arrays)
 
     run_refined = None
     if elem_apply_lo is not None:
@@ -704,12 +722,12 @@ def _build_sharded_runner(part: MeshPartition, ctx, elem_ops, elem_apply,
         @functools.partial(jax.jit, static_argnames=("precond",
                                                      "stagnation_window",
                                                      "fault"))
-        def run_refined(b_global, tol, max_iter, precond="jacobi", x0=None,
-                        stagnation_window=0, fault=None):
+        def _run_refined(a, b_global, tol, max_iter, precond="jacobi",
+                         x0=None, stagnation_window=0, fault=None):
             batched = b_global.ndim > (2 if d > 1 else 1)
             fdof = _validate_fault(fault) if fault is not None else None
-            b_loc = localize(jnp.asarray(b_global, jnp.float32))
-            x0_loc = localize(jnp.asarray(x0, jnp.float32)) \
+            b_loc = localize(jnp.asarray(b_global, jnp.float32), a)
+            x0_loc = localize(jnp.asarray(x0, jnp.float32), a) \
                 if x0 is not None else jnp.zeros_like(b_loc)
             body = smap(
                 functools.partial(refined_body,
@@ -721,13 +739,15 @@ def _build_sharded_runner(part: MeshPartition, ctx, elem_ops, elem_apply,
                           ops_specs_lo) + idx_specs,
                 out_specs=(pe, pe, pe, pe, pe, pe))
             x_loc, it, rr, r0, brk, st = body(
-                b_loc, diag_loc, jnp.asarray(tol),
-                jnp.asarray(max_iter, jnp.int32), x0_loc, elem_ops,
-                elem_ops_lo, *idx_args)
-            return PCGResult(globalize(x_loc), it[0], rr[0], r0[0], brk[0],
-                             st[0])
+                b_loc, a["diag"], jnp.asarray(tol),
+                jnp.asarray(max_iter, jnp.int32), x0_loc, a["ops"],
+                a["ops_lo"], *a["idx"])
+            return PCGResult(globalize(x_loc, a), it[0], rr[0], r0[0],
+                             brk[0], st[0])
 
-    return apply_global, run_pcg, run_refined
+        run_refined = functools.partial(_run_refined, arrays)
+
+    return apply_global, run_pcg, run_refined, arrays
 
 
 def rhs_from_solution(problem: NekboneProblem, x_true: jnp.ndarray) -> jnp.ndarray:

@@ -34,6 +34,9 @@ from repro.resilience.status import SolveStatus, classify
 
 __all__ = ["PCGResult", "pcg", "pcg_block", "refine", "owned_dot"]
 
+# at the default precision a TPU computes an fp32 vdot in bf16
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _up(u: jnp.ndarray) -> jnp.ndarray:
     """Upcast sub-fp32 floats for reduction accumulation.
@@ -172,7 +175,7 @@ def pcg(a_op: Callable[[jnp.ndarray], jnp.ndarray],
     """
     if dot is None:
         def dot(u, v):
-            return jnp.vdot(_up(u), _up(v))
+            return jnp.vdot(_up(u), _up(v), precision=HIGHEST)
     if precond is None:
         def precond(r):
             return r
@@ -439,7 +442,7 @@ def refine(a_hi, a_lo, b: jnp.ndarray,
                 return jnp.sum(uv, axis=tuple(range(uv.ndim - 1)))
         else:
             def dot(u, v):
-                return jnp.vdot(_up(u), _up(v))
+                return jnp.vdot(_up(u), _up(v), precision=HIGHEST)
     b32 = jnp.asarray(b, jnp.float32)
     runner = pcg_block if batched else pcg
 

@@ -11,9 +11,12 @@ tensor axis — O(N1^4) FLOPs per element instead of the O(N1^6) of a full
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 __all__ = ["grad_ref", "grad_ref_transpose", "apply_dr", "apply_ds", "apply_dt"]
+
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _einsum(subscripts: str, dhat: jnp.ndarray, x: jnp.ndarray):
@@ -22,12 +25,13 @@ def _einsum(subscripts: str, dhat: jnp.ndarray, x: jnp.ndarray):
     For sub-fp32 float inputs (the bf16 twin operator) the dot must not
     accumulate at the storage width — the `AccumulationDtype` contract
     forbids it everywhere — so accumulate in f32 and round once at the
-    end.  The >= fp32 path is left untouched (bit-identical)."""
+    end.  HIGHEST precision: a TPU otherwise runs an fp32 contraction as
+    one bf16 pass (the CPU computes every precision the same way)."""
     out_dt = jnp.promote_types(dhat.dtype, x.dtype)
     if jnp.issubdtype(out_dt, jnp.floating) and jnp.finfo(out_dt).bits < 32:
-        return jnp.einsum(subscripts, dhat, x,
+        return jnp.einsum(subscripts, dhat, x, precision=HIGHEST,
                           preferred_element_type=jnp.float32).astype(out_dt)
-    return jnp.einsum(subscripts, dhat, x)
+    return jnp.einsum(subscripts, dhat, x, precision=HIGHEST)
 
 
 def apply_dr(x: jnp.ndarray, dhat: jnp.ndarray) -> jnp.ndarray:

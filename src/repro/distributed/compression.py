@@ -20,9 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.context import (PARTIAL_MANUAL_SHARD_MAP,
-                                       shard_map_compat)
-
 __all__ = ["quantize_int8", "dequantize_int8", "ef_compress",
            "halo_compress", "halo_decompress", "compressed_crosspod_grads"]
 
@@ -115,15 +112,10 @@ def compressed_crosspod_grads(loss_fn, params, batch, mesh,
         metrics = jax.tree.map(lambda m: jax.lax.pmean(m, pod_axis), metrics)
         return loss, metrics, grads
 
-    # Partially-manual (manual over 'pod', automatic over 'data'/'model')
-    # needs jax >= 0.5 (see PARTIAL_MANUAL_SHARD_MAP).  The 0.4.x fallback
-    # goes fully manual with pod-only specs — numerically identical
-    # (loss_fn sees the whole pod batch either way); the in-pod data/model
-    # sharding of the loss is simply not exploited there.
-    manual = {pod_axis} if PARTIAL_MANUAL_SHARD_MAP else None
-    shard = shard_map_compat(
-        per_pod, mesh=mesh, manual_axes=manual,
+    # partially manual: manual over 'pod', automatic over 'data'/'model'.
+    # The replication check is off: the gather+sum makes the outputs
+    # pod-replicated, which the static varying-axes check can't infer.
+    shard = jax.shard_map(
+        per_pod, mesh=mesh, axis_names={pod_axis}, check_vma=False,
         in_specs=(P(), P(pod_axis)), out_specs=(P(), P(), P()))
-    # the replication check is off in the shim: the gather+sum makes the
-    # outputs pod-replicated, which the static varying-axes check can't infer
     return shard(params, batch)

@@ -19,38 +19,7 @@ import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
 __all__ = ["ShardCtx", "SolverShardCtx", "EXCHANGES", "HALO_COMPRESS",
-           "make_ctx", "make_solver_ctx", "parse_grid_arg", "constraint",
-           "shard_map_compat", "PARTIAL_MANUAL_SHARD_MAP"]
-
-# jax >= 0.5 exposes top-level jax.shard_map; that release is also where
-# DIFFERENTIATING a partially-manual shard_map works (0.4.x trips an XLA
-# SPMD partitioner check — IsManualSubgroup mismatch).  Callers that want
-# partial-manual mode gate on this single probe instead of re-testing.
-PARTIAL_MANUAL_SHARD_MAP = hasattr(jax, "shard_map")
-
-
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs, manual_axes=None):
-    """`jax.shard_map` across jax versions (the 0.4.x <-> 0.5 API split).
-
-    jax 0.5 renamed the replication check (`check_rep` -> `check_vma`) and
-    the partial-manual selector (`auto=<complement>` -> `axis_names=
-    <manual set>`) and promoted shard_map out of jax.experimental.  Both
-    call styles mean the same thing; this shim always disables the
-    replication check (our bodies psum to replicated outputs, which the
-    static check cannot infer) and takes the MANUAL axis set.
-    """
-    if PARTIAL_MANUAL_SHARD_MAP:
-        kw = {"check_vma": False}
-        if manual_axes is not None:
-            kw["axis_names"] = set(manual_axes)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kw = {"check_rep": False}
-    if manual_axes is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - set(manual_axes)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
+           "make_ctx", "make_solver_ctx", "parse_grid_arg", "constraint"]
 
 
 class ShardCtx(NamedTuple):

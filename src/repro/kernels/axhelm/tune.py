@@ -14,8 +14,9 @@ the static heuristic with measurement:
 
 Autotuning is opt-in (``block_elems="auto"`` on the ops/axhelm entry points
 or an explicit :func:`autotune` call); the default resolution order is
-in-process cache -> JSON cache -> :func:`default_block_elems` heuristic, so
-untuned call sites never pay a timing sweep.
+in-process cache -> JSON cache -> :func:`model_block_elems` (the VMEM model
+capped by the :func:`default_block_elems` heuristic), so untuned call sites
+never pay a timing sweep.
 """
 
 from __future__ import annotations
@@ -36,14 +37,24 @@ __all__ = [
     "block_vmem_bytes",
     "feasible_block_elems",
     "get_block_elems",
+    "model_block_elems",
     "autotune",
     "cache_path",
 ]
 
-# Half of a v5e core's ~16 MiB VMEM: leave headroom for Pallas' pipelining
-# (double-buffered operand windows) and compiler temporaries.
-VMEM_BUDGET_BYTES = 8 << 20
-_CANDIDATES = (1, 2, 4, 8, 16, 32, 64, 128, 256)
+# Mosaic's default scoped-VMEM limit on a v5e TensorCore.  The model below
+# over-predicts what that compiler allocates, so 2 MiB of headroom is for
+# configurations the calibration did not cover (DESIGN.md §6).
+VMEM_LIMIT_BYTES = 16 << 20
+VMEM_BUDGET_BYTES = VMEM_LIMIT_BYTES - (2 << 20)
+# A compiled kernel's block is a multiple of the 8-row fp32 sublane tile
+# (`kernel.build_axhelm_call` refuses anything else short of the whole
+# launch); every candidate is one.
+_TILE = 8
+_CANDIDATES = (8, 16, 32, 64, 128, 256)
+# Compiler scratch of the fp32-precision matmuls, independent of the block
+# (2.9-3.6 MiB in the calibration, rounded up).
+_FIXED_SCRATCH = 9 << 19
 
 CACHE_ENV = "REPRO_AXHELM_TUNE_CACHE"
 _DEFAULT_CACHE = os.path.join(os.path.expanduser("~"), ".cache", "repro",
@@ -54,63 +65,74 @@ _LOCK = threading.Lock()
 
 
 def default_block_elems(n1: int, d: int, nrhs: int = 1) -> int:
-    """Static fallback: EB so the contraction matmuls see ~128 rows but the
-    X block stays under ~1 MiB fp32 (the pre-autotuner heuristic).  The RHS
-    batch multiplies both the matmul rows and the X block the same way a
-    component axis does."""
-    rows_per_elem = d * nrhs * n1 * n1
+    """Static fallback: EB so the contraction matmuls see ~128 rows (one row
+    per element column, nrhs*d columns per element) while the X block stays
+    under ~1 MiB fp32."""
+    rows_per_elem = d * nrhs
     eb = max(1, int(np.ceil(128 / rows_per_elem)))
     while eb > 1 and eb * d * nrhs * n1**3 * 4 > 1 << 20:
         eb //= 2
     return eb
 
 
+def _tile_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """VMEM bytes of a (rows, cols) buffer in the (8|16, 128) tiled layout."""
+    sub = 32 // min(itemsize, 4)
+    return -(-rows // sub) * sub * -(-cols // 128) * 128 * itemsize
+
+
 def block_vmem_bytes(variant: str, n1: int, d: int, dtype, eb: int,
                      helmholtz: bool = False, nrhs: int = 1) -> int:
-    """Estimated VMEM bytes for one grid step.
+    """Estimated VMEM bytes for one grid step of the lane-dense kernel.
 
-    Counts the HBM-backed operand windows at their storage dtype plus the
-    fp32 intermediates the kernel materializes (xr/xs/xt, gxr/gxs/gxt, and
-    the recalculated factor fields for the on-the-fly variants).  X, Y and
-    the gradient intermediates scale with the RHS batch `nrhs`; the
+    * operand windows, double-buffered by the Pallas pipeline, at their
+      storage dtype in the tiled layout: X and Y (eb, nrhs*d*N1^3), the
+      per-element geometry window and the lambda planes;
+    * the broadcast constants (Kronecker D̂ factors, Jacobian weights),
+      double-buffered too;
+    * the fp32 working set: eight (eb*nrhs*d, N1^3) planes (stacked X, the
+      three gradients, the three factor products, Y — the Y accumulator is
+      fp32 whatever the storage dtype) plus seven per-element factor
+      planes shared by every column;
+    * `_FIXED_SCRATCH`.
+
+    X, Y and the working planes scale with the RHS batch `nrhs`; the
     geometry and lambda windows do NOT — they are per-element and shared by
-    every RHS, which is the whole point of the batching.
+    every RHS, which is the whole point of the batching.  Calibrated against
+    the compiler for a described v5e (DESIGN.md §6): it over-predicts every
+    measured configuration.
     """
+    from repro.kernels.axhelm.kernel import chunk_width
+
     ws = jnp.dtype(dtype).itemsize
     fp32 = 4
     nodes = n1 ** 3
-    total = eb * nrhs * d * nodes * ws           # x operand window
-    # the y block is the kernel's ACCUMULATOR, fp32 no matter how narrow
-    # the storage dtype (preferred_element_type=f32 on every contraction)
-    # — charging it at bf16 width undercounted a bf16 block by n/8 of its
-    # real footprint and admitted block sizes that overflow VMEM
-    total += eb * nrhs * d * nodes * max(ws, fp32)
-    total += 6 * eb * nrhs * d * nodes * fp32   # xr/xs/xt + gxr/gxs/gxt
+    cols = nrhs * d
+    w = chunk_width(n1)
     if variant == "precomputed":
-        total += eb * nodes * (6 + (1 if helmholtz else 0)) * ws
-        if helmholtz:
-            total += 2 * eb * nodes * ws     # lam0, lam1
+        geom = (6 + (1 if helmholtz else 0)) * nodes
+        consts = []
     elif variant == "parallelepiped":
-        total += eb * 7 * ws
-        total += 7 * eb * nodes * fp32       # broadcast g6 + gwj
-        if helmholtz:
-            total += 2 * eb * nodes * ws
-    elif variant == "trilinear":
-        total += eb * 24 * ws
-        total += (9 + 7) * eb * nodes * fp32  # J~ block + g6/gwj
-        if helmholtz:
-            total += 2 * eb * nodes * ws
-    elif variant == "merged":
-        total += eb * 24 * ws
-        total += 2 * eb * nodes * ws         # Lam2, Lam3
-        total += (9 + 12) * eb * nodes * fp32  # J~ + adj(K~) + g6
-    elif variant == "partial":
-        total += eb * 24 * ws
-        total += eb * nodes * ws             # gScale
-        total += (9 + 12) * eb * nodes * fp32
+        geom, consts = 7, [(7, 7 * nodes)]
+    elif variant in ("trilinear", "merged", "partial"):
+        geom, consts = 24, [(9, nodes)]
+        if variant == "trilinear":
+            consts.append((1, nodes))
     else:
         raise ValueError(f"unknown axhelm variant {variant!r}")
-    return total
+    if variant == "partial":
+        lams = 1                                  # gScale
+    elif variant == "merged" or helmholtz:
+        lams = 2                                  # Lam2/Lam3, or lam0/lam1
+    else:
+        lams = 0
+    windows = (2 * _tile_bytes(eb, cols * nodes, ws)
+               + _tile_bytes(eb, geom, ws) + lams * _tile_bytes(eb, nodes, ws))
+    consts += [(w, 2 * w), (nodes, nodes)]
+    total = 2 * windows + 2 * sum(_tile_bytes(r, c, ws) for r, c in consts)
+    total += 8 * eb * cols * nodes * fp32
+    total += 7 * eb * nodes * fp32
+    return total + _FIXED_SCRATCH
 
 
 def feasible_block_elems(variant: str, n1: int, d: int, dtype,
@@ -123,22 +145,29 @@ def feasible_block_elems(variant: str, n1: int, d: int, dtype,
            if (e_total is None or eb <= max(int(e_total), 1))
            and block_vmem_bytes(variant, n1, d, dtype, eb, helmholtz,
                                 nrhs=nrhs) <= budget]
-    return out or [1]
+    return out or [_CANDIDATES[0]]
+
+
+def _interpreting(interpret: Optional[bool]) -> bool:
+    """None -> interpreted off a TPU, compiled on one (as ops.axhelm)."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
 
 
 def _backend_tag(interpret: Optional[bool]) -> str:
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return jax.default_backend() + ("-interpret" if interpret else "")
+    return jax.default_backend() + ("-interpret" if _interpreting(interpret)
+                                    else "")
 
 
 def _config_key(variant: str, n1: int, d: int, dtype,
                 helmholtz: bool, nrhs: int = 1) -> str:
-    # "v2/": the VMEM-model schema version.  v1 entries were tuned with a
-    # model that charged the fp32 y accumulator at the storage width, so a
-    # v1 bf16 winner can be a block size the corrected model rejects as
-    # over-budget — those entries must MISS, not resolve.
-    key = f"v2/{variant}/n1={n1}/d={d}/" \
+    # "v3/": the VMEM-model schema version.  v1 entries were tuned with a
+    # model that charged the fp32 y accumulator at the storage width, v2
+    # entries for the kernel layout before the lane-dense one (block sizes
+    # that are no multiple of the sublane tile) — those entries must MISS,
+    # not resolve.
+    key = f"v3/{variant}/n1={n1}/d={d}/" \
           f"{jnp.dtype(dtype).name}/helm={int(helmholtz)}"
     # nrhs=1 keeps the pre-batching key so existing caches stay valid
     return key if nrhs == 1 else key + f"/nrhs={nrhs}"
@@ -210,7 +239,8 @@ def _save_json(backend: str, key: str, entry: dict) -> None:
         pass  # a read-only cache dir must never break the solve
 
 
-def _clamp_to_elems(eb: int, e_total: Optional[int]) -> int:
+def _clamp_to_elems(eb: int, e_total: Optional[int],
+                    compiled: bool = False) -> int:
     """Clamp a tuned block size to the caller's element count.
 
     The cache is keyed per (variant, N, d, dtype) configuration, but the
@@ -224,11 +254,20 @@ def _clamp_to_elems(eb: int, e_total: Optional[int]) -> int:
     and the larger one just takes more grid steps, or the full EP when the
     degenerate all-interface partition falls back to one unsplit launch.
     The cached winner stays unclamped; only this call's resolution
-    shrinks."""
+    shrinks.  Below the smallest candidate an interpreted launch takes its
+    own element count (one block, no padding).  A `compiled` block stays a
+    multiple of the sublane tile whatever the clamp — one padded tile
+    there — because the block also runs launches larger than `e_total`
+    (the neighbour exchange's other sub-batch)."""
+    if compiled:
+        eb = -(-eb // _TILE) * _TILE
     if e_total is None or eb <= e_total:
         return eb
-    under = [c for c in _CANDIDATES if c <= max(int(e_total), 1)]
-    return max(under) if under else 1
+    e = max(int(e_total), 1)
+    under = [c for c in _CANDIDATES if c <= e]
+    if under:
+        return max(under)
+    return _TILE if compiled else e
 
 
 def get_block_elems(variant: str, n1: int, d: int, dtype,
@@ -245,20 +284,30 @@ def get_block_elems(variant: str, n1: int, d: int, dtype,
     batched solve drives the same configuration.
     """
     backend = _backend_tag(interpret)
+    compiled = not _interpreting(interpret)
     key = _config_key(variant, n1, d, dtype, helmholtz, nrhs)
     with _LOCK:
         hit = _MEM_CACHE.get((backend, key))
     if hit is not None:
-        return _clamp_to_elems(hit, e_total)
+        return _clamp_to_elems(hit, e_total, compiled)
     eb = _cache_entry(backend, key)
     if eb is not None:
         with _LOCK:
             _MEM_CACHE[(backend, key)] = eb
-        return _clamp_to_elems(eb, e_total)
+        return _clamp_to_elems(eb, e_total, compiled)
     if autotune_now:
         eb, _ = autotune(variant, n1 - 1, d=d, dtype=dtype,
                          helmholtz=helmholtz, interpret=interpret, nrhs=nrhs)
-        return _clamp_to_elems(eb, e_total)
+        return _clamp_to_elems(eb, e_total, compiled)
+    return model_block_elems(variant, n1, d, dtype, helmholtz, e_total,
+                             nrhs=nrhs)
+
+
+def model_block_elems(variant: str, n1: int, d: int, dtype,
+                      helmholtz: bool = False,
+                      e_total: Optional[int] = None, nrhs: int = 1) -> int:
+    """The block size from the VMEM model alone — no cache, no timing: the
+    largest feasible candidate at or below `default_block_elems`."""
     cand = feasible_block_elems(variant, n1, d, dtype, helmholtz, e_total,
                                 nrhs=nrhs)
     heuristic = default_block_elems(n1, d, nrhs)
@@ -288,7 +337,8 @@ def _synthetic_inputs(variant, n, d, dtype, helmholtz, e, nrhs=1):
     if variant == "precomputed":
         from repro.core import geometry
         f = geometry.factors_trilinear(verts, b)
-        geom = jnp.concatenate([f.g, f.gwj[..., None]], axis=-1)
+        geom = jnp.concatenate([jnp.moveaxis(f.g, -1, 1), f.gwj[:, None]],
+                               axis=1)
         if helmholtz:
             lam0 = jnp.ones(node, dtype)
             lam1 = jnp.full(node, 0.1, dtype)

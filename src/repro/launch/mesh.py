@@ -13,18 +13,10 @@ __all__ = ["make_production_mesh", "make_test_mesh"]
 
 
 def _make_mesh(shape, axes):
-    """`jax.make_mesh` across jax versions.
-
-    `jax.sharding.AxisType` only exists from jax 0.5 (where `make_mesh`
-    wants explicit axis types to silence the Auto/Explicit migration); on
-    0.4.x the kwarg itself is unknown, so the call is version-guarded —
-    both paths produce a fully-Auto mesh.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """A fully-Auto mesh (explicit axis types silence the Auto/Explicit
+    migration warning of `jax.make_mesh`)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

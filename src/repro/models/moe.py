@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.context import ShardCtx, shard_map_compat
+from repro.distributed.context import ShardCtx
 from repro.models.config import ModelConfig
 from repro.models.params import ParamSpec
 
@@ -198,9 +198,9 @@ def moe_apply(p, x: jnp.ndarray, cfg: ModelConfig,
         body = functools.partial(_moe_core, cfg=cfg, capacity=cap,
                                  ep_axis=ep_axis, token_axes=token_axes,
                                  use_a2a=use_a2a)
-        shard = shard_map_compat(
+        shard = jax.shard_map(
             lambda xx, rw, g, u, dn: _shard_body(body, xx, rw, g, u, dn),
-            mesh=ctx.mesh,
+            mesh=ctx.mesh, check_vma=False,
             in_specs=(x_spec, P(None, None), P(ep_axis, None, None),
                       P(ep_axis, None, None), P(ep_axis, None, None)),
             out_specs=(x_spec, P()))

@@ -67,14 +67,16 @@ def test_auto_backend_resolution():
 
 
 def test_backend_env_default(monkeypatch):
+    """The default backend is "reference" whatever the environment says:
+    no variable switches the element kernel behind the caller's back."""
     b = basis(2)
     verts = jnp.asarray(_mesh("trilinear", 2).verts, jnp.float32)
-    monkeypatch.setenv(core_ax.BACKEND_ENV, "pallas")
-    op = core_ax.make_axhelm("trilinear", b, verts, dtype=jnp.float32)
-    assert op.backend == "pallas"
-    monkeypatch.delenv(core_ax.BACKEND_ENV)
+    monkeypatch.setenv("REPRO_AXHELM_BACKEND", "pallas")
     op = core_ax.make_axhelm("trilinear", b, verts, dtype=jnp.float32)
     assert op.backend == "reference"
+    op = core_ax.make_axhelm("trilinear", b, verts, dtype=jnp.float32,
+                             backend="pallas")
+    assert op.backend == "pallas"
 
 
 # ---------------------------------------------------------------------------

@@ -42,20 +42,31 @@ def test_bf16_block_charges_fp32_accumulator():
 
     The kernels accumulate in fp32 regardless of input width
     (`preferred_element_type` on every contraction), so a bf16 block's y
-    window occupies fp32 bytes of VMEM.  The pre-fix model halved it with
-    the storage dtype and admitted bf16 block sizes whose real footprint
-    overflows the budget."""
+    accumulator — like the rest of the fp32 working set — occupies fp32
+    bytes of VMEM.  The pre-fix model halved it with the storage dtype and
+    admitted bf16 block sizes whose real footprint overflows the budget."""
     eb, n1 = 16, 8
     nodes = n1 ** 3
-    # trilinear/bf16: x at 2B, y at 4B (accumulator), 6 fp32 gradient
-    # intermediates, 24 vertex coords at 2B, (9 + 7) fp32 factor fields
-    expect = eb * (nodes * (2 + 4 + 6 * 4 + 16 * 4) + 24 * 2)
+    tile = tune._tile_bytes
+
+    def storage(ws):
+        # double-buffered x/y/vertex windows and broadcast constants, tiled
+        windows = 2 * tile(eb, nodes, ws) + tile(eb, 24, ws)
+        consts = (tile(9, nodes, ws) + tile(1, nodes, ws)
+                  + tile(128, 256, ws)
+                  + tile(nodes, nodes, ws))
+        return 2 * windows + 2 * consts
+
+    # stacked x, 3 gradients, 3 factor products, the y accumulator; plus 7
+    # per-element factor planes — all fp32 whatever the storage dtype
+    working = (8 + 7) * eb * nodes * 4 + tune._FIXED_SCRATCH
     got = tune.block_vmem_bytes("trilinear", n1, 1, jnp.bfloat16, eb)
-    assert got == expect, (got, expect)
-    # halving the storage dtype narrows the x and vertex windows ONLY —
-    # pre-fix the difference also carried a (phantom) narrowed y window
+    assert got == storage(2) + working, (got, storage(2) + working)
+    # halving the storage dtype narrows the HBM-backed windows and
+    # constants ONLY — pre-fix the difference also carried a (phantom)
+    # narrowed y accumulator
     f32 = tune.block_vmem_bytes("trilinear", n1, 1, jnp.float32, eb)
-    assert f32 - got == eb * (nodes * 2 + 24 * 2), (f32, got)
+    assert f32 - got == storage(4) - storage(2), (f32, got)
 
 
 def test_v1_cache_entries_miss_under_v2_schema(isolated_cache):
@@ -66,7 +77,7 @@ def test_v1_cache_entries_miss_under_v2_schema(isolated_cache):
     isolated_cache.write_text(json.dumps(
         {backend: {v1_key: {"block_elems": 256}}}))
     assert tune._config_key(
-        "trilinear", 3, 1, jnp.bfloat16, False).startswith("v2/")
+        "trilinear", 3, 1, jnp.bfloat16, False).startswith("v3/")
     eb = tune.get_block_elems("trilinear", 3, 1, jnp.bfloat16)
     assert eb != 256
     assert eb in tune.feasible_block_elems("trilinear", 3, 1, jnp.bfloat16)
@@ -113,6 +124,28 @@ def test_cached_winner_clamped_to_shard_elems(isolated_cache):
                                 e_total=64) == 64
     # the cached entry itself must stay unclamped
     assert tune._MEM_CACHE[(backend, key)] == 64
+
+
+def test_compiled_resolution_keeps_the_sublane_tile(isolated_cache):
+    """Resolved for the compiled kernel, a block is a multiple of the 8-row
+    sublane tile however far it is clamped (it also runs launches larger
+    than the one it was clamped to); interpreted, a launch below every
+    candidate runs as one block of its own size."""
+    backend = tune._backend_tag(False)
+    key = tune._config_key("trilinear", 3, 1, jnp.float32, False)
+    tune._MEM_CACHE[(backend, key)] = 64
+    assert tune.get_block_elems("trilinear", 3, 1, jnp.float32, e_total=3,
+                                interpret=False) == 8
+    assert tune.get_block_elems("trilinear", 3, 1, jnp.float32, e_total=20,
+                                interpret=False) == 16
+    # a hand-picked winner off the tile rounds up to it
+    tune._MEM_CACHE[(backend, key)] = 12
+    assert tune.get_block_elems("trilinear", 3, 1, jnp.float32,
+                                interpret=False) == 16
+    ibackend = tune._backend_tag(True)
+    tune._MEM_CACHE[(ibackend, key)] = 64
+    assert tune.get_block_elems("trilinear", 3, 1, jnp.float32, e_total=3,
+                                interpret=True) == 3
 
 
 def test_block_elems_auto_entry_point(isolated_cache, rng):

@@ -20,7 +20,31 @@ def _mesh_verts(n, nx=2, ny=2, nz=1, seed=1, dtype=jnp.float32):
 def _geom_precomputed(verts, b):
     coords = geometry.node_coords(verts, b)
     f = geometry.factors_discrete(coords, b)
-    return jnp.concatenate([f.g, f.gwj[..., None]], axis=-1)
+    # the kernels' planar layout: (E, 7, N1, N1, N1)
+    return jnp.concatenate([jnp.moveaxis(f.g, -1, 1), f.gwj[:, None]],
+                           axis=1)
+
+
+def test_compiled_kernel_off_tpu_raises(rng):
+    """interpret=False demands the compiled kernel: off a TPU it raises,
+    naming the backend, instead of quietly interpreting — from the kernel
+    wrapper and from the solver's setup alike."""
+    from repro.core import nekbone
+
+    b = basis(2)
+    verts = _mesh_verts(2)
+    x = jnp.asarray(rng.standard_normal((verts.shape[0],) + (b.n1,) * 3),
+                    jnp.float32)
+    with pytest.raises(RuntimeError, match="'cpu'"):
+        kops.axhelm(x, b, "trilinear", verts, interpret=False)
+    mesh = mesh_gen.box_mesh(2, 2, 1, 2)
+    with pytest.raises(RuntimeError, match="interpret=False"):
+        nekbone.setup_problem(mesh, variant="trilinear", backend="pallas",
+                              block_elems=8, interpret=False)
+    # the default interprets off a TPU, so the CPU tests run the kernels
+    y = kops.axhelm(x, b, "trilinear", verts)
+    np.testing.assert_allclose(y, kops.reference(x, b, "trilinear", verts),
+                               rtol=2e-5, atol=1e-4)
 
 
 @pytest.mark.parametrize("n", [2, 3, 7])

@@ -376,6 +376,18 @@ meshes = [mesh_gen.deform_trilinear(mesh_gen.box_mesh(3, 3, 2, 3), seed=3)]
 if devices == 2:
     meshes.append(mesh_gen.deform_trilinear(mesh_gen.box_mesh(5, 1, 1, 3),
                                             seed=4))
+
+
+def kappa(mesh, helm):
+    # 2-norm condition number of the assembled operator (dense, tiny)
+    p = nekbone.setup_problem(mesh, variant="trilinear", helmholtz=helm,
+                              dtype=jnp.float32)
+    a = np.asarray(p.op(jnp.eye(mesh.n_global, dtype=jnp.float32)),
+                   np.float64)
+    ev = np.linalg.eigvalsh((a + a.T) / 2)
+    return float(ev[-1] / ev[0])
+
+
 rng = np.random.default_rng(0)
 for mesh in meshes:
     for nrhs in (1, 4):
@@ -400,26 +412,40 @@ for mesh in meshes:
                 r1 = nekbone.solve(sh, b, tol=%(tol)g, max_iter=300)
                 it0 = np.atleast_1d(np.asarray(r0.iterations)).tolist()
                 it1 = np.atleast_1d(np.asarray(r1.iterations)).tolist()
+                def cols(a):
+                    return jnp.linalg.norm(a.reshape(mesh.n_global, -1),
+                                           axis=0)
                 print(json.dumps({
                     "elements": len(mesh.verts), "helm": helm,
                     "backend": backend, "nrhs": nrhs,
                     "it_psum": it0, "it_nbr": it1,
-                    "dx": float(jnp.max(jnp.abs(r1.x - r0.x)))}))
+                    "dx_rel": np.asarray(cols(r1.x - r0.x)
+                                         / cols(r0.x)).tolist(),
+                    "tol_rel": np.asarray(%(tol)g / cols(b)).tolist(),
+                    "kappa": kappa(mesh, helm)}))
 """
 
 
 @pytest.mark.parametrize("devices", [2, 4, 8])
 def test_neighbour_solve_matches_psum(devices):
     """exchange="neighbour" solve == exchange="psum" solve within ±1 PCG
-    iteration, both equations/backends, nrhs 1 and 4, non-divisible E."""
+    iteration, both equations/backends, nrhs 1 and 4, non-divisible E.
+
+    The solutions agree to what the tolerance allows: each solve stops at
+    a relative residual of max(tol/||b||, eps) (eps: the fp32 floor, the
+    two exchanges sum interface contributions in different orders), and
+    a relative residual r bounds the relative error by kappa(A) * r — so
+    the two answers differ by at most twice that."""
     rows = _run(_PARITY_SCRIPT % {"devices": devices, "tol": TOL}, devices)
     # per mesh: nrhs=1 x {poisson, helmholtz} x {ref, pallas} = 4 rows,
     # nrhs=4 x {poisson, helmholtz} x ref = 2 rows
     assert len(rows) == (12 if devices == 2 else 6)
+    eps = float(np.finfo(np.float32).eps)
     for r in rows:
         for a, b in zip(r["it_psum"], r["it_nbr"]):
             assert abs(a - b) <= 1, r
-        assert r["dx"] < 1e-3, r
+        for dx, tol_rel in zip(r["dx_rel"], r["tol_rel"]):
+            assert dx <= 2 * r["kappa"] * max(tol_rel, eps), r
 
 
 def test_gather_sharded_neighbour_matches_psum_gather():
@@ -456,9 +482,8 @@ def test_gather_sharded_neighbour_matches_psum_gather():
             b = gs.gather_sharded_neighbour(y, lid, nl, rounds, ctx.axis)
             return a, b
 
-        from repro.distributed.context import shard_map_compat
-        smap = shard_map_compat(
-            body, mesh=ctx.mesh,
+        smap = jax.shard_map(
+            body, mesh=ctx.mesh, check_vma=False,
             in_specs=(pe,) * (4 + len(nbr)), out_specs=(pe, pe))
         rng = np.random.default_rng(0)
         for shape in [(s * ep, n1, n1, n1), (s * ep, n1, n1, n1, 3)]:

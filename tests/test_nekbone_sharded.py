@@ -158,6 +158,49 @@ def test_sharded_op_matches_global_op():
         assert r["rel"] < 1e-5, r
 
 
+def test_sharded_arrays_live_split_and_enter_as_arguments():
+    """Every per-shard array sits split over the mesh after setup (each
+    device holds exactly its rows), and the jitted PCG runner receives
+    them as arguments: none is captured as a constant of its program,
+    where every call or compile would move the whole array."""
+    rows = _run(textwrap.dedent("""
+        import json
+        import jax, jax.numpy as jnp
+        import numpy as np
+        from repro.core import mesh_gen, nekbone
+        from repro.distributed.context import make_solver_ctx
+        mesh = mesh_gen.deform_trilinear(mesh_gen.box_mesh(4, 2, 2, 3),
+                                         seed=3)
+        b = jnp.ones(mesh.n_global, jnp.float32)
+        for exchange in ("psum", "neighbour"):
+            ctx = make_solver_ctx(devices=4, exchange=exchange)
+            sh = nekbone.setup_problem(mesh, variant="trilinear",
+                                       dtype=jnp.float32, shard_ctx=ctx)
+            leaves = jax.tree.leaves(sh.shard_arrays)
+            split = all(
+                sorted(s.device.id for s in a.addressable_shards)
+                == [0, 1, 2, 3]
+                and all(4 * s.data.shape[0] == a.shape[0]
+                        for s in a.addressable_shards)
+                for a in leaves)
+            traced = jax.make_jaxpr(lambda v: sh.run_pcg(v, 1e-6, 5))(b)
+            shapes = {tuple(a.shape) for a in leaves}
+            captured = [tuple(np.shape(c))
+                        for e in traced.jaxpr.eqns if "jaxpr" in e.params
+                        for c in getattr(e.params["jaxpr"], "consts", ())
+                        if tuple(np.shape(c)) in shapes]
+            res = nekbone.solve(sh, b, tol=1e-4, max_iter=200)
+            print(json.dumps({"exchange": exchange, "leaves": len(leaves),
+                              "split": split, "captured": captured,
+                              "status": int(res.status)}))
+    """), devices=4)
+    assert [r["exchange"] for r in rows] == ["psum", "neighbour"]
+    for r in rows:
+        assert r["leaves"] >= 8 and r["split"], r
+        assert r["captured"] == [], r
+        assert r["status"] == 0, r          # SolveStatus.CONVERGED
+
+
 def test_single_device_ctx_collapses_to_unsharded():
     """make_solver_ctx on 1 device returns None -> today's exact path."""
     from repro.distributed.context import make_solver_ctx

@@ -139,9 +139,13 @@ def test_bucketed_single_request_bit_parity(poisson):
 
 def test_padded_columns_are_bit_neutral(poisson):
     """3 requests pack into bucket 4 (one zero-padded column): every real
-    column is bit-identical to the direct unpadded 3-column block solve,
-    and per-request reports carry length-1 arrays (padding never reaches
-    a SolveReport)."""
+    column is bit-identical to the direct block solve at the bucket width
+    (the same compiled shape, a zero fourth column), agrees with the
+    unpadded 3-column solve to the solve tolerance, and per-request
+    reports carry length-1 arrays (padding never reaches a SolveReport).
+
+    Bit identity across the two widths is no claim: they are two compiled
+    programs, free to round differently."""
     _, prob = poisson
     rng = np.random.default_rng(4)
     bs = [_rhs(prob, rng) for _ in range(3)]
@@ -151,12 +155,26 @@ def test_padded_columns_are_bit_neutral(poisson):
     for r in reqs:
         svc.submit(r)
     assert svc.step() == 3
-    ref = solve_resilient(prob, jnp.stack(bs, axis=-1), tol=TOL,
-                          max_iter=200)
+    padded = solve_resilient(
+        prob, jnp.stack(bs + [jnp.zeros_like(bs[0])], axis=-1), tol=TOL,
+        max_iter=200)
+    unpadded = solve_resilient(prob, jnp.stack(bs, axis=-1), tol=TOL,
+                               max_iter=200)
+    assert int(padded.iterations[3]) == 0        # the pad never iterates
+    # the acceptance bar of solve_resilient: each answer's true residual
+    # is within verify_factor * max(tol, eps ||b||), so two answers to one
+    # b differ by at most twice that through A
+    eps = float(jnp.finfo(jnp.float32).eps)
     for j, req in enumerate(reqs):
-        np.testing.assert_array_equal(np.asarray(req.report.x),
-                                      np.asarray(ref.x[..., j]))
-        assert int(req.report.iterations[0]) == int(ref.iterations[j])
+        x = np.asarray(req.report.x)
+        np.testing.assert_array_equal(x, np.asarray(padded.x[..., j]))
+        assert int(req.report.iterations[0]) == int(padded.iterations[j])
+        assert abs(int(req.report.iterations[0])
+                   - int(unpadded.iterations[j])) <= 1
+        bar = RetryPolicy().verify_factor * max(
+            TOL, eps * float(jnp.linalg.norm(bs[j])))
+        diff = prob.op(jnp.asarray(x) - unpadded.x[..., j])
+        assert float(jnp.linalg.norm(diff)) <= 2 * bar
         assert req.report.status.shape == (1,)
         assert len(req.report.rung) == 1
 
