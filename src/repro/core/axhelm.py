@@ -25,6 +25,7 @@ from typing import Callable, NamedTuple, Optional
 
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import geometry, sumfact
 from repro.core.geometry import GeomFactors, JT_SCALE
 from repro.core.spectral import SpectralBasis
@@ -415,7 +416,7 @@ def make_axhelm_elem_ops(variant: str, basis: SpectralBasis,
                                lam1=elem_ops.get("lam1"),
                                block_elems=block_elems, interpret=interpret,
                                **kw)
-        return elem_ops, apply, backend
+        return elem_ops, obs.scoped("axhelm", apply), backend
 
     dhat = jnp.asarray(basis.dhat, dtype=dtype)
     # Per-element lambda FIELDS ride in elem_ops — they have an element
@@ -475,4 +476,4 @@ def make_axhelm_elem_ops(variant: str, basis: SpectralBasis,
         def apply(x, elem_ops):
             return axhelm_partial(x, elem_ops["verts"], basis, dhat,
                                   elem_ops["gscale"])
-    return elem_ops, apply, backend
+    return elem_ops, obs.scoped("axhelm", apply), backend

@@ -20,6 +20,7 @@ from typing import NamedTuple, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.distributed.compression import halo_compress, halo_decompress
 
 __all__ = [
@@ -33,7 +34,8 @@ __all__ = [
 
 def scatter(x_global: jnp.ndarray, global_ids: jnp.ndarray) -> jnp.ndarray:
     """Q x: copy global dof values to element-local nodes."""
-    return x_global[global_ids]
+    with obs.scope("gs.q"):
+        return x_global[global_ids]
 
 
 def gather(y_local: jnp.ndarray, global_ids: jnp.ndarray,
@@ -66,15 +68,16 @@ def gather(y_local: jnp.ndarray, global_ids: jnp.ndarray,
     acc_dt = jnp.promote_types(dt, jnp.float32) \
         if jnp.issubdtype(dt, jnp.floating) and jnp.finfo(dt).bits < 32 \
         else dt
-    if y_local.ndim == global_ids.ndim:  # scalar field
-        out = jax.ops.segment_sum(y_local.reshape(-1).astype(acc_dt), ids,
-                                  num_segments=n_global)
-    else:
-        # vector field: trailing component axis
-        d = y_local.shape[-1]
-        vals = y_local.reshape(-1, d).astype(acc_dt)
-        out = jax.ops.segment_sum(vals, ids, num_segments=n_global)
-    return out.astype(dt)
+    with obs.scope("gs.qt"):
+        if y_local.ndim == global_ids.ndim:  # scalar field
+            out = jax.ops.segment_sum(y_local.reshape(-1).astype(acc_dt),
+                                      ids, num_segments=n_global)
+        else:
+            # vector field: trailing component axis
+            d = y_local.shape[-1]
+            vals = y_local.reshape(-1, d).astype(acc_dt)
+            out = jax.ops.segment_sum(vals, ids, num_segments=n_global)
+        return out.astype(dt)
 
 
 def dssum(y_local: jnp.ndarray, global_ids: jnp.ndarray,
@@ -115,8 +118,9 @@ def shared_contrib(y_dofs: jnp.ndarray, shared_idx: jnp.ndarray,
     y_dofs: (L[, d]) per-shard local dof values; shared_idx: (NS,) local
     slots (trash where absent); shared_present: (NS,) bool.
     """
-    vals = y_dofs[shared_idx]
-    return jnp.where(_expand_mask(shared_present, vals), vals, 0.0)
+    with obs.scope("gs.iface"):
+        vals = y_dofs[shared_idx]
+        return jnp.where(_expand_mask(shared_present, vals), vals, 0.0)
 
 
 def apply_shared(y_dofs: jnp.ndarray, shared_idx: jnp.ndarray,
@@ -126,7 +130,8 @@ def apply_shared(y_dofs: jnp.ndarray, shared_idx: jnp.ndarray,
     Absent interface dofs carry the trash slot index, so their writes land
     in the trash slot (whose value is never read unmasked).
     """
-    return y_dofs.at[shared_idx].set(summed)
+    with obs.scope("gs.iface"):
+        return y_dofs.at[shared_idx].set(summed)
 
 
 def exchange_shared(y_dofs: jnp.ndarray, shared_idx: jnp.ndarray,
@@ -140,7 +145,8 @@ def exchange_shared(y_dofs: jnp.ndarray, shared_idx: jnp.ndarray,
     operator application: the batch rides along as extra psum columns.
     """
     contrib = shared_contrib(y_dofs, shared_idx, shared_present)
-    summed = jax.lax.psum(contrib, axis_name)
+    with obs.scope("exchange"):
+        summed = jax.lax.psum(contrib, axis_name)
     return apply_shared(y_dofs, shared_idx, summed)
 
 
@@ -240,20 +246,26 @@ def neighbour_start(y_dofs: jnp.ndarray, rounds: Sequence[NeighbourRound],
     of the codec rides its own ppermute with the same static perm tables;
     `neighbour_finish` must be called with the same `compress`.
     """
+    def permute(p, perm):
+        with obs.scope("exchange"):
+            return jax.lax.ppermute(p, axis_name, perm)
+
     recvs = []
-    for r in rounds:
-        send_lo = shared_contrib(y_dofs, r.lo_idx, r.lo_mask)
-        send_hi = shared_contrib(y_dofs, r.hi_idx, r.hi_mask)
-        if compress is not None:
-            # each codec part (payload, scales, ...) rides its own permute
-            recv_hi = tuple(jax.lax.ppermute(p, axis_name, r.fwd_perm)
-                            for p in halo_compress(send_lo, compress))
-            recv_lo = tuple(jax.lax.ppermute(p, axis_name, r.bwd_perm)
-                            for p in halo_compress(send_hi, compress))
-        else:
-            recv_hi = jax.lax.ppermute(send_lo, axis_name, r.fwd_perm)
-            recv_lo = jax.lax.ppermute(send_hi, axis_name, r.bwd_perm)
-        recvs.append((recv_hi, recv_lo))
+    with obs.scope("gs.iface"):
+        for r in rounds:
+            send_lo = shared_contrib(y_dofs, r.lo_idx, r.lo_mask)
+            send_hi = shared_contrib(y_dofs, r.hi_idx, r.hi_mask)
+            if compress is not None:
+                # each codec part (payload, scales, ...) rides its own
+                # permute
+                recv_hi = tuple(permute(p, r.fwd_perm)
+                                for p in halo_compress(send_lo, compress))
+                recv_lo = tuple(permute(p, r.bwd_perm)
+                                for p in halo_compress(send_hi, compress))
+            else:
+                recv_hi = permute(send_lo, r.fwd_perm)
+                recv_lo = permute(send_hi, r.bwd_perm)
+            recvs.append((recv_hi, recv_lo))
     return recvs
 
 
@@ -286,22 +298,23 @@ def neighbour_finish(y_dofs: jnp.ndarray,
     between up to 4 shards).  At fp32 the same reordering is the usual
     harmless 1-ulp-level associativity noise.
     """
-    acc_dt = jnp.promote_types(y_dofs.dtype, jnp.float32)
-    decoded = []
-    for recv_hi, recv_lo in recvs:
-        if compress is not None:
-            recv_hi = halo_decompress(recv_hi, compress, y_dofs.dtype)
-            recv_lo = halo_decompress(recv_lo, compress, y_dofs.dtype)
-        decoded.append((recv_hi, recv_lo))
-    acc = jnp.zeros(y_dofs.shape, acc_dt)
-    for r, (recv_hi, _) in reversed(list(zip(rounds, decoded))):
-        part = jnp.where(_expand_mask(r.hi_mask, recv_hi), recv_hi, 0.0)
-        acc = acc.at[r.hi_idx].add(part.astype(acc_dt))
-    acc = acc + y_dofs.astype(acc_dt)
-    for r, (_, recv_lo) in zip(rounds, decoded):
-        part = jnp.where(_expand_mask(r.lo_mask, recv_lo), recv_lo, 0.0)
-        acc = acc.at[r.lo_idx].add(part.astype(acc_dt))
-    return acc.astype(y_dofs.dtype)
+    with obs.scope("gs.iface"):
+        acc_dt = jnp.promote_types(y_dofs.dtype, jnp.float32)
+        decoded = []
+        for recv_hi, recv_lo in recvs:
+            if compress is not None:
+                recv_hi = halo_decompress(recv_hi, compress, y_dofs.dtype)
+                recv_lo = halo_decompress(recv_lo, compress, y_dofs.dtype)
+            decoded.append((recv_hi, recv_lo))
+        acc = jnp.zeros(y_dofs.shape, acc_dt)
+        for r, (recv_hi, _) in reversed(list(zip(rounds, decoded))):
+            part = jnp.where(_expand_mask(r.hi_mask, recv_hi), recv_hi, 0.0)
+            acc = acc.at[r.hi_idx].add(part.astype(acc_dt))
+        acc = acc + y_dofs.astype(acc_dt)
+        for r, (_, recv_lo) in zip(rounds, decoded):
+            part = jnp.where(_expand_mask(r.lo_mask, recv_lo), recv_lo, 0.0)
+            acc = acc.at[r.lo_idx].add(part.astype(acc_dt))
+        return acc.astype(y_dofs.dtype)
 
 
 def halo_self_round(y_dofs: jnp.ndarray, shared_idx: jnp.ndarray,
@@ -328,8 +341,9 @@ def halo_self_round(y_dofs: jnp.ndarray, shared_idx: jnp.ndarray,
     wire).
     """
     vals = shared_contrib(y_dofs, shared_idx, shared_present)
-    dec = halo_decompress(halo_compress(vals, compress), compress,
-                          y_dofs.dtype)
+    with obs.scope("gs.iface"):
+        dec = halo_decompress(halo_compress(vals, compress), compress,
+                              y_dofs.dtype)
     return apply_shared(y_dofs, shared_idx, dec)
 
 

@@ -19,6 +19,8 @@ from typing import NamedTuple
 
 import numpy as np
 
+from repro import obs
+
 __all__ = ["BoxMesh", "MeshPartition", "box_mesh", "deform_affine",
            "deform_trilinear", "partition_elements", "auto_grid",
            "normalize_grid"]
@@ -44,6 +46,7 @@ class BoxMesh(NamedTuple):
     order: int
 
 
+@obs.span("setup.mesh")
 def box_mesh(nx: int, ny: int, nz: int, order: int,
              lengths=(1.0, 1.0, 1.0)) -> BoxMesh:
     """Uniform box mesh on [0, Lx] x [0, Ly] x [0, Lz]."""
@@ -464,6 +467,7 @@ def deform_affine(mesh: BoxMesh, matrix: np.ndarray | None = None,
     return mesh._replace(verts=verts)
 
 
+@obs.span("setup.mesh")
 def deform_trilinear(mesh: BoxMesh, amplitude: float = 0.08,
                      seed: int = 0) -> BoxMesh:
     """Smoothly warp the shared vertex grid: general trilinear elements.

@@ -18,6 +18,7 @@ sharded region.  See DESIGN.md.
 from __future__ import annotations
 
 import functools
+import itertools
 import warnings
 from typing import NamedTuple, Optional
 
@@ -26,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core import axhelm as axhelm_mod
 from repro.core import gather_scatter as gs
 from repro.core import geometry
@@ -107,20 +109,23 @@ def _global_op(element_op, mesh: BoxMesh, mask):
         x_in = x
         bshape = x.shape[1:]
         if mask is not None:
-            m = gs._expand_mask(mask, x)
-            x = jnp.where(m, 0.0, x)
+            with obs.scope("vec.mask"):
+                m = gs._expand_mask(mask, x)
+                x = jnp.where(m, 0.0, x)
         xf = x.reshape((ng, -1)) if bshape else x
         xl = gs.scatter(xf, ids)                     # (E, N1,N1,N1[, c])
-        if bshape:
-            xl = jnp.moveaxis(xl, -1, 1)             # (E, c, N1,N1,N1)
-        yl = element_op(xl)
-        if bshape:
-            yl = jnp.moveaxis(yl, 1, -1)
+        with obs.scope("axhelm"):
+            if bshape:
+                xl = jnp.moveaxis(xl, -1, 1)         # (E, c, N1,N1,N1)
+            yl = element_op(xl)
+            if bshape:
+                yl = jnp.moveaxis(yl, 1, -1)
         y = gs.gather(yl, ids, ng)
         if bshape:
             y = y.reshape((ng,) + bshape)
         if mask is not None:
-            y = jnp.where(m, x_in, y)
+            with obs.scope("vec.mask"):
+                y = jnp.where(m, x_in, y)
         return y
 
     return apply
@@ -149,6 +154,7 @@ def _global_diag(mesh: BoxMesh, b: SpectralBasis, factors, lam0, lam1,
 PRECISIONS = (None, "bf16_x32")
 
 
+@obs.span("setup.problem")
 def setup_problem(mesh: BoxMesh, variant: str = "precomputed", d: int = 1,
                   helmholtz: bool = False, lam0=None, lam1=None,
                   dirichlet: bool | None = None,
@@ -215,8 +221,9 @@ def setup_problem(mesh: BoxMesh, variant: str = "precomputed", d: int = 1,
     part = None
     e_shard = len(mesh.verts)
     if n_shards > 1:
-        part = partition_elements(mesh, n_shards,
-                                  grid=getattr(shard_ctx, "grid", None))
+        with obs.span("setup.partition"):
+            part = partition_elements(mesh, n_shards,
+                                      grid=getattr(shard_ctx, "grid", None))
         e_shard = part.e_per_shard
         if getattr(shard_ctx, "exchange", "psum") == "neighbour":
             # overlapped exchange: ONE launch plan decides both the kernel
@@ -236,16 +243,17 @@ def setup_problem(mesh: BoxMesh, variant: str = "precomputed", d: int = 1,
                     f"'auto')) shrinks the interface surface and restores "
                     f"the overlap window.", UserWarning, stacklevel=2)
     block_arg = block_elems
-    block_elems = _resolve_auto_block(variant, b, d, helmholtz, dtype,
-                                      backend, block_elems, interpret, nrhs,
-                                      e_shard)
-    block_lo = None
-    if precision == "bf16_x32":
-        # the bf16 operator tunes its own block size: smaller windows,
-        # but a full-width fp32 accumulator (see kernels/axhelm/tune.py)
-        block_lo = _resolve_auto_block(variant, b, d, helmholtz,
-                                       jnp.bfloat16, backend, block_arg,
-                                       interpret, nrhs, e_shard)
+    with obs.span("setup.block"):
+        block_elems = _resolve_auto_block(variant, b, d, helmholtz, dtype,
+                                          backend, block_elems, interpret,
+                                          nrhs, e_shard)
+        block_lo = None
+        if precision == "bf16_x32":
+            # the bf16 operator tunes its own block size: smaller windows,
+            # but a full-width fp32 accumulator (see kernels/axhelm/tune.py)
+            block_lo = _resolve_auto_block(variant, b, d, helmholtz,
+                                           jnp.bfloat16, backend, block_arg,
+                                           interpret, nrhs, e_shard)
 
     if part is not None:
         return _setup_problem_sharded(
@@ -253,20 +261,23 @@ def setup_problem(mesh: BoxMesh, variant: str = "precomputed", d: int = 1,
             backend, block_elems, interpret, shard_ctx, part,
             precision, block_lo)
 
-    op = axhelm_mod.make_axhelm(variant, b, verts, lam0=lam0, lam1=lam1,
-                                helmholtz=helmholtz, dtype=dtype,
-                                backend=backend, block_elems=block_elems,
-                                interpret=interpret)
+    with obs.span("setup.geometry"):
+        op = axhelm_mod.make_axhelm(variant, b, verts, lam0=lam0, lam1=lam1,
+                                    helmholtz=helmholtz, dtype=dtype,
+                                    backend=backend, block_elems=block_elems,
+                                    interpret=interpret)
     apply = _global_op(op.apply, mesh, mask)
-    diag = _global_diag(mesh, b, op.factors, lam0, lam1, helmholtz, d, mask,
-                        dtype)
+    with obs.span("setup.diag"):
+        diag = _global_diag(mesh, b, op.factors, lam0, lam1, helmholtz, d,
+                            mask, dtype)
     op_lo_apply = None
     if precision == "bf16_x32":
         lo = jnp.bfloat16
-        op_lo = axhelm_mod.make_axhelm(
-            variant, b, verts.astype(lo), lam0=_cast_opt(lam0, lo),
-            lam1=_cast_opt(lam1, lo), helmholtz=helmholtz, dtype=lo,
-            backend=backend, block_elems=block_lo, interpret=interpret)
+        with obs.span("setup.geometry"):
+            op_lo = axhelm_mod.make_axhelm(
+                variant, b, verts.astype(lo), lam0=_cast_opt(lam0, lo),
+                lam1=_cast_opt(lam1, lo), helmholtz=helmholtz, dtype=lo,
+                backend=backend, block_elems=block_lo, interpret=interpret)
         op_lo_apply = _global_op(op_lo.apply, mesh, mask)
     return NekboneProblem(apply, diag, mask, mesh, b, d, helmholtz, variant,
                           op.backend, precision, op_lo_apply)
@@ -375,13 +386,15 @@ def _setup_problem_sharded(mesh: BoxMesh, b: SpectralBasis, variant: str,
             lam = _partition_lam_field(lam, part, dtype)
         lam_sh.append(lam)
     flat_verts = jnp.asarray(part.verts.reshape(-1, 8, 3), dtype=dtype)
-    elem_ops, elem_apply, backend_used = axhelm_mod.make_axhelm_elem_ops(
-        variant, b, flat_verts, lam0=lam_sh[0], lam1=lam_sh[1],
-        helmholtz=helmholtz, dtype=dtype, backend=backend,
-        block_elems=block_elems, interpret=interpret)
+    with obs.span("setup.geometry"):
+        elem_ops, elem_apply, backend_used = axhelm_mod.make_axhelm_elem_ops(
+            variant, b, flat_verts, lam0=lam_sh[0], lam1=lam_sh[1],
+            helmholtz=helmholtz, dtype=dtype, backend=backend,
+            block_elems=block_elems, interpret=interpret)
     verts = jnp.asarray(mesh.verts, dtype=dtype)
-    diag = _global_diag(mesh, b, _diag_factors(variant, b, verts), lam0,
-                        lam1, helmholtz, d, mask, dtype)
+    with obs.span("setup.diag"):
+        diag = _global_diag(mesh, b, _diag_factors(variant, b, verts), lam0,
+                            lam1, helmholtz, d, mask, dtype)
     elem_ops_lo = elem_apply_lo = None
     if precision == "bf16_x32":
         # a SECOND operand set at bfloat16 over the same partition: the
@@ -389,10 +402,13 @@ def _setup_problem_sharded(mesh: BoxMesh, b: SpectralBasis, variant: str,
         # fp32 operator, just half-width (and codec-compressed on the
         # wire when ctx.compress says so)
         lo = jnp.bfloat16
-        elem_ops_lo, elem_apply_lo, _ = axhelm_mod.make_axhelm_elem_ops(
-            variant, b, flat_verts.astype(lo), lam0=_cast_opt(lam_sh[0], lo),
-            lam1=_cast_opt(lam_sh[1], lo), helmholtz=helmholtz, dtype=lo,
-            backend=backend, block_elems=block_lo, interpret=interpret)
+        with obs.span("setup.geometry"):
+            elem_ops_lo, elem_apply_lo, _ = axhelm_mod.make_axhelm_elem_ops(
+                variant, b, flat_verts.astype(lo),
+                lam0=_cast_opt(lam_sh[0], lo),
+                lam1=_cast_opt(lam_sh[1], lo), helmholtz=helmholtz,
+                dtype=lo, backend=backend, block_elems=block_lo,
+                interpret=interpret)
     apply_global, run_pcg, run_refined, arrays = _build_sharded_runner(
         part, shard_ctx, elem_ops, elem_apply, mask, diag, d,
         mesh.n_global, elem_ops_lo=elem_ops_lo,
@@ -465,26 +481,30 @@ def _build_sharded_runner(part: MeshPartition, ctx, elem_ops, elem_apply,
             for j in range(len(part.nbr_offsets))
             for t in (part.nbr_lo_idx[j], part.nbr_lo_mask[j],
                       part.nbr_hi_idx[j], part.nbr_hi_mask[j]))
-    arrays = place(dict(
-        ops=elem_ops, ops_lo=elem_ops_lo, diag=diag[l2g], l2g=l2g,
-        idx=(part.local_ids.reshape(s * ep, n1, n1, n1),
-             part.shared_idx.reshape(-1), part.shared_present.reshape(-1),
-             part.owned_mask.reshape(-1), part.valid_mask.reshape(-1),
-             mask_loc) + nbr_args))
+    with obs.span("setup.place"):
+        arrays = place(dict(
+            ops=elem_ops, ops_lo=elem_ops_lo, diag=diag[l2g], l2g=l2g,
+            idx=(part.local_ids.reshape(s * ep, n1, n1, n1),
+                 part.shared_idx.reshape(-1),
+                 part.shared_present.reshape(-1),
+                 part.owned_mask.reshape(-1), part.valid_mask.reshape(-1),
+                 mask_loc) + nbr_args))
 
     ops_specs = jax.tree.map(lambda _: pe, elem_ops)
     idx_specs = (pe,) * len(arrays["idx"])
     expand = gs._expand_mask
 
     def localize(xg, a):
-        xl = xg[a["l2g"]]
-        return jnp.where(expand(a["idx"][4], xl), xl, 0)     # valid slots
+        with obs.scope("gs.q"):
+            xl = xg[a["l2g"]]
+            return jnp.where(expand(a["idx"][4], xl), xl, 0)  # valid slots
 
     def globalize(xl, a):
-        w = expand(a["idx"][3], xl)                         # owned dofs
-        shape = (n_global,) + xl.shape[1:]
-        return jnp.zeros(shape, xl.dtype).at[a["l2g"]].add(
-            jnp.where(w, xl, 0))
+        with obs.scope("gs.qt"):
+            w = expand(a["idx"][3], xl)                     # owned dofs
+            shape = (n_global,) + xl.shape[1:]
+            return jnp.zeros(shape, xl.dtype).at[a["l2g"]].add(
+                jnp.where(w, xl, 0))
 
     def _make_a_op(apply_fn, wire):
         """The per-shard operator body for ONE element-kernel apply fn.
@@ -496,12 +516,14 @@ def _build_sharded_runner(part: MeshPartition, ctx, elem_ops, elem_apply,
 
         def _elem_batch(xl, eo, lid, lo, hi, bshape):
             """axhelm + local gather on element slots [lo, hi)."""
-            xb = xl[lo:hi]
-            eob = jax.tree.map(lambda a: a[lo:hi], eo)
-            yb = apply_fn(xb, eob)
-            if bshape:
-                yb = jnp.moveaxis(yb, 1, -1)
-            return gs.gather(yb, lid[lo:hi], nl)
+            with obs.scope("axhelm"):
+                xb = xl[lo:hi]
+                eob = jax.tree.map(lambda a: a[lo:hi], eo)
+                yb = apply_fn(xb, eob)
+                if bshape:
+                    yb = jnp.moveaxis(yb, 1, -1)
+            with obs.scope("gs.qt"):
+                return gs.gather(yb, lid[lo:hi], nl)
 
         def a_op_local(x, eo, lid, sidx, spres, own, val, m, *nbr,
                        it=None, fault=None, fdof=None):
@@ -532,11 +554,14 @@ def _build_sharded_runner(part: MeshPartition, ctx, elem_ops, elem_apply,
             x_in = x
             bshape = x.shape[1:]
             if has_mask:
-                x = jnp.where(expand(m, x), 0.0, x)
+                with obs.scope("vec.mask"):
+                    x = jnp.where(expand(m, x), 0.0, x)
             xf = x.reshape((x.shape[0], -1)) if bshape else x
-            xl = xf[lid]                              # (EP, N1,N1,N1[, c])
+            with obs.scope("gs.q"):
+                xl = xf[lid]                          # (EP, N1,N1,N1[, c])
             if bshape:
-                xl = jnp.moveaxis(xl, -1, 1)
+                with obs.scope("axhelm"):
+                    xl = jnp.moveaxis(xl, -1, 1)
             fire = None
             if fault is not None:
                 fire = jnp.logical_and(
@@ -548,7 +573,9 @@ def _build_sharded_runner(part: MeshPartition, ctx, elem_ops, elem_apply,
                 recvs = gs.neighbour_start(y, rounds, axis,
                                            compress=wire)  # in flight
                 if split:
-                    y = y + _elem_batch(xl, eo, lid, cut, ep, bshape)
+                    y_interior = _elem_batch(xl, eo, lid, cut, ep, bshape)
+                    with obs.scope("gs.qt"):
+                        y = y + y_interior
                 if wire is not None:
                     # interior elements touch no shared dof, so this still
                     # rounds exactly the partials the sends encoded; every
@@ -564,12 +591,13 @@ def _build_sharded_runner(part: MeshPartition, ctx, elem_ops, elem_apply,
                 y = jnp.where(fire, y_pre, y)
             if bshape:
                 y = y.reshape((nl,) + bshape)
-            if has_mask:
-                y = jnp.where(expand(m, y), x_in, y)
-            # dead-element and padding slots must stay exactly zero:
-            # anything accumulating there would feed inf/nan into later
-            # iterations
-            y = jnp.where(expand(val, y), y, 0)
+            with obs.scope("vec.mask"):
+                if has_mask:
+                    y = jnp.where(expand(m, y), x_in, y)
+                # dead-element and padding slots must stay exactly zero:
+                # anything accumulating there would feed inf/nan into
+                # later iterations
+                y = jnp.where(expand(val, y), y, 0)
             if fault is not None and fault.mode != "drop_exchange":
                 y = fault_inject.poison(y, fdof, fire, fault)
             return y
@@ -610,7 +638,8 @@ def _build_sharded_runner(part: MeshPartition, ctx, elem_ops, elem_apply,
 
         pre = None
         if use_jacobi:
-            inv_diag = 1.0 / dg
+            with obs.scope("vec.precond"):
+                inv_diag = 1.0 / dg
 
             def pre(r):
                 # the diagonal has no RHS axis; broadcast it over the batch
@@ -667,8 +696,9 @@ def _build_sharded_runner(part: MeshPartition, ctx, elem_ops, elem_apply,
         x_loc, it, rr, r0, brk, st = body(
             b_loc, a["diag"], jnp.asarray(tol),
             jnp.asarray(max_iter, jnp.int32), x0_loc, a["ops"], *a["idx"])
-        return PCGResult(globalize(x_loc, a), it[0], rr[0], r0[0], brk[0],
-                         st[0])
+        with obs.scope("vec.update"):
+            return PCGResult(globalize(x_loc, a), it[0], rr[0], r0[0],
+                             brk[0], st[0])
 
     run_pcg = functools.partial(_run_pcg, arrays)
 
@@ -706,7 +736,8 @@ def _build_sharded_runner(part: MeshPartition, ctx, elem_ops, elem_apply,
             pre = None
             if use_jacobi:
                 # the inner iterates are bf16; so is their preconditioner
-                inv_lo = (1.0 / dg).astype(jnp.bfloat16)
+                with obs.scope("vec.precond"):
+                    inv_lo = (1.0 / dg).astype(jnp.bfloat16)
 
                 def pre(r):
                     return (inv_lo[..., None] if batched else inv_lo) * r
@@ -742,8 +773,9 @@ def _build_sharded_runner(part: MeshPartition, ctx, elem_ops, elem_apply,
                 b_loc, a["diag"], jnp.asarray(tol),
                 jnp.asarray(max_iter, jnp.int32), x0_loc, a["ops"],
                 a["ops_lo"], *a["idx"])
-            return PCGResult(globalize(x_loc, a), it[0], rr[0], r0[0],
-                             brk[0], st[0])
+            with obs.scope("vec.update"):
+                return PCGResult(globalize(x_loc, a), it[0], rr[0], r0[0],
+                                 brk[0], st[0])
 
         run_refined = functools.partial(_run_refined, arrays)
 
@@ -760,6 +792,9 @@ def rhs_from_solution(problem: NekboneProblem, x_true: jnp.ndarray) -> jnp.ndarr
         x_true = jnp.where(gs._expand_mask(problem.mask, x_true), 0.0,
                            x_true)
     return problem.op(x_true)
+
+
+_SOLVE_IDS = itertools.count()   # the `solve` attr of each solve.host span
 
 
 def solve(problem: NekboneProblem, b_rhs: jnp.ndarray, precond: str = "jacobi",
@@ -786,7 +821,18 @@ def solve(problem: NekboneProblem, b_rhs: jnp.ndarray, precond: str = "jacobi",
     (a `resilience.inject.FaultSpec`, static) deterministically corrupts
     one operator application — the fault-injection harness used by the
     resilience tests; leave None in production.
+
+    The host work is the span ``solve.host``; on a sharded problem called
+    outside any jit, ``solve.place`` puts the right-hand side (and `x0`)
+    on every device and ``solve.launch`` dispatches the jitted runner.
     """
+    with obs.span("solve.host", solve=next(_SOLVE_IDS)):
+        return _solve(problem, b_rhs, precond, tol, max_iter, x0,
+                      stagnation_window, fault)
+
+
+def _solve(problem, b_rhs, precond, tol, max_iter, x0, stagnation_window,
+           fault) -> PCGResult:
     if precond not in ("jacobi", "copy"):
         raise ValueError(f"unknown preconditioner {precond!r}")
     base = 1 if problem.d == 1 else 2
@@ -808,8 +854,17 @@ def solve(problem: NekboneProblem, b_rhs: jnp.ndarray, precond: str = "jacobi",
     refined = getattr(problem, "precision", None) == "bf16_x32"
     if isinstance(problem, ShardedNekboneProblem):
         runner = problem.run_refined if refined else problem.run_pcg
-        return runner(b_rhs, tol, max_iter, precond=precond, x0=x0,
-                      stagnation_window=stagnation_window, fault=fault)
+        if not isinstance(b_rhs, jax.core.Tracer):
+            # what the runner's dispatch would do: replicate the
+            # arguments over the mesh
+            with obs.span("solve.place"):
+                everywhere = NamedSharding(problem.shard_ctx.mesh, P())
+                b_rhs = jax.device_put(b_rhs, everywhere)
+                if x0 is not None:
+                    x0 = jax.device_put(x0, everywhere)
+        with obs.span("solve.launch"):
+            return runner(b_rhs, tol, max_iter, precond=precond, x0=x0,
+                          stagnation_window=stagnation_window, fault=fault)
     if refined:
         # mixed precision: fp32 outer residual/correction through the
         # full-precision operator, bf16 inner sweeps through op_lo (a
@@ -821,7 +876,8 @@ def solve(problem: NekboneProblem, b_rhs: jnp.ndarray, precond: str = "jacobi",
                                               problem.mesh.global_ids)
         pre = None
         if precond == "jacobi":
-            inv_lo = (1.0 / problem.diag).astype(jnp.bfloat16)
+            with obs.scope("vec.precond"):
+                inv_lo = (1.0 / problem.diag).astype(jnp.bfloat16)
 
             def pre(r):
                 return (inv_lo[..., None] if batched else inv_lo) * r
@@ -834,7 +890,8 @@ def solve(problem: NekboneProblem, b_rhs: jnp.ndarray, precond: str = "jacobi",
                                           problem.mesh.global_ids)
     pre = None
     if precond == "jacobi":
-        inv_diag = 1.0 / problem.diag
+        with obs.scope("vec.precond"):
+            inv_diag = 1.0 / problem.diag
 
         def pre(r):
             return (inv_diag[..., None] if batched else inv_diag) * r

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.resilience.status import SolveStatus, classify
 
 __all__ = ["PCGResult", "pcg", "pcg_block", "refine", "owned_dot"]
@@ -84,7 +85,8 @@ def owned_dot(weight: jnp.ndarray, axis_name: Optional[str] = None,
             part = jnp.sum(prod)
         if axis_name is None:
             return part
-        return jax.lax.psum(part, axis_name)
+        with obs.scope("exchange"):
+            return jax.lax.psum(part, axis_name)
 
     return dot
 
@@ -179,16 +181,22 @@ def pcg(a_op: Callable[[jnp.ndarray], jnp.ndarray],
     if precond is None:
         def precond(r):
             return r
+    dot = obs.scoped("vec.dot", dot)
+    precond = obs.scoped("vec.precond", precond)
     a2 = _iter_op(a_op)
 
-    x = jnp.zeros_like(b) if x0 is None else x0
-    r = b - a2(x, jnp.asarray(_INIT_ITER, jnp.int32))
+    with obs.scope("vec.update"):
+        x = jnp.zeros_like(b) if x0 is None else x0
+    ax = a2(x, jnp.asarray(_INIT_ITER, jnp.int32))
+    with obs.scope("vec.update"):
+        r = b - ax
     z = precond(r)
     p = z
     rz = dot(r, z)
     rr = dot(r, r)
-    r0 = jnp.sqrt(rr)
-    tol2 = (tol * tol)
+    with obs.scope("vec.update"):
+        r0 = jnp.sqrt(rr)
+        tol2 = (tol * tol)
     window = jnp.asarray(stagnation_window, jnp.int32)
     win_on = window > 0
 
@@ -205,8 +213,13 @@ def pcg(a_op: Callable[[jnp.ndarray], jnp.ndarray],
                                jnp.logical_and(rr > tol2, healthy))
 
     def body(state):
+        # A(p) outside the update's scope: its own layers name its work
+        ap = a2(state[3], state[6])
+        with obs.scope("vec.update"):
+            return advance(state, ap)
+
+    def advance(state, ap):
         x, r, z, p, rz, rr, it, brk, div, stag, stall, best = state
-        ap = a2(p, it)
         pap = dot(p, ap)
         # Lanczos breakdown guard: p.Ap <= 0 with the residual still above
         # tolerance means A is not SPD along p (rank-deficient direction) —
@@ -250,9 +263,10 @@ def pcg(a_op: Callable[[jnp.ndarray], jnp.ndarray],
              jnp.array(False), jnp.array(False), jnp.array(False),
              jnp.array(0, jnp.int32), rr)
     (x, r, _, _, _, rr, it, brk, div, stag, _, _) = \
-        jax.lax.while_loop(cond, body, state)
-    status = classify(rr, tol2, brk, div, stag)
-    return PCGResult(x, it, jnp.sqrt(rr), r0, brk, status)
+        jax.lax.while_loop(obs.scoped("vec.update", cond), body, state)
+    with obs.scope("vec.update"):
+        status = classify(rr, tol2, brk, div, stag)
+        return PCGResult(x, it, jnp.sqrt(rr), r0, brk, status)
 
 
 def pcg_block(a_op: Callable[[jnp.ndarray], jnp.ndarray],
@@ -296,16 +310,22 @@ def pcg_block(a_op: Callable[[jnp.ndarray], jnp.ndarray],
     if precond is None:
         def precond(r):
             return r
+    dot = obs.scoped("vec.dot", dot)
+    precond = obs.scoped("vec.precond", precond)
     a2 = _iter_op(a_op)
 
-    x = jnp.zeros_like(b) if x0 is None else x0
-    r = b - a2(x, jnp.asarray(_INIT_ITER, jnp.int32))
+    with obs.scope("vec.update"):
+        x = jnp.zeros_like(b) if x0 is None else x0
+    ax = a2(x, jnp.asarray(_INIT_ITER, jnp.int32))
+    with obs.scope("vec.update"):
+        r = b - ax
     z = precond(r)
     p = z
     rz = dot(r, z)
     rr = dot(r, r)
-    r0 = jnp.sqrt(rr)
-    tol2 = (tol * tol)
+    with obs.scope("vec.update"):
+        r0 = jnp.sqrt(rr)
+        tol2 = (tol * tol)
     nrhs = b.shape[-1]
     window = jnp.asarray(stagnation_window, jnp.int32)
     win_on = window > 0
@@ -316,9 +336,14 @@ def pcg_block(a_op: Callable[[jnp.ndarray], jnp.ndarray],
         return jnp.logical_and(it[-1] < max_iter, jnp.any(live))
 
     def body(state):
+        # A(p) outside the update's scope: its own layers name its work
+        ap = a2(state[3], state[6][-1])
+        with obs.scope("vec.update"):
+            return advance(state, ap)
+
+    def advance(state, ap):
         x, r, z, p, rz, rr, it, brk, div, stag, stall, best = state
         active = (rr > tol2) & ~brk & ~div & ~stag  # (nrhs,) live columns
-        ap = a2(p, it[-1])
         pap = dot(p, ap)
         # Lanczos breakdown on an ACTIVE column: p.Ap <= 0 while its
         # residual is still above tolerance means A is not SPD along that
@@ -371,9 +396,10 @@ def pcg_block(a_op: Callable[[jnp.ndarray], jnp.ndarray],
              jnp.zeros((nrhs,), bool), jnp.zeros((nrhs,), bool),
              jnp.zeros((nrhs,), jnp.int32), rr)
     (x, r, _, _, _, rr, it, brk, div, stag, _, _) = \
-        jax.lax.while_loop(cond, body, state)
-    status = classify(rr, tol2, brk, div, stag)
-    return PCGResult(x, it[:nrhs], jnp.sqrt(rr), r0, brk, status)
+        jax.lax.while_loop(obs.scoped("vec.update", cond), body, state)
+    with obs.scope("vec.update"):
+        status = classify(rr, tol2, brk, div, stag)
+        return PCGResult(x, it[:nrhs], jnp.sqrt(rr), r0, brk, status)
 
 
 def refine(a_hi, a_lo, b: jnp.ndarray,
@@ -443,14 +469,21 @@ def refine(a_hi, a_lo, b: jnp.ndarray,
         else:
             def dot(u, v):
                 return jnp.vdot(_up(u), _up(v), precision=HIGHEST)
-    b32 = jnp.asarray(b, jnp.float32)
     runner = pcg_block if batched else pcg
-
-    x = jnp.zeros_like(b32) if x0 is None else jnp.asarray(x0, jnp.float32)
-    r = (b32 - a_hi(x)).astype(jnp.float32)
+    inner_dot = dot
+    dot = obs.scoped("vec.dot", dot)
+    with obs.scope("vec.update"):
+        b32 = jnp.asarray(b, jnp.float32)
+        x = jnp.zeros_like(b32) if x0 is None \
+            else jnp.asarray(x0, jnp.float32)
+    ax = a_hi(x)
+    with obs.scope("vec.update"):
+        r = (b32 - ax).astype(jnp.float32)
     rr = dot(r, r)
-    r0 = jnp.sqrt(rr)
-    tol2 = tol * tol
+    with obs.scope("vec.update"):
+        r0 = jnp.sqrt(rr)
+    with obs.scope("vec.update"):
+        tol2 = tol * tol
     it_shape = rr.shape  # () or (nrhs,)
     mi = jnp.asarray(max_iter, jnp.int32)
 
@@ -461,28 +494,37 @@ def refine(a_hi, a_lo, b: jnp.ndarray,
 
     def body(state):
         x, r, rr, it, sweeps, div, stag, stall = state
-        active = (rr > tol2) & ~div & ~stag
-        rnorm = jnp.sqrt(rr)
-        safe = jnp.where(active & (rnorm > 0), rnorm, 1.0)
-        # frozen columns get a zero inner RHS: their inner column has
-        # r0 = 0, converges at iteration 0, and block-PCG's freeze keeps
-        # it from perturbing live columns
-        r_hat = jnp.where(active, r / safe, 0.0).astype(lo_dtype)
-        # adaptive inner target: the reduction this sweep still needs is
-        # tol/||r|| per column; take the tightest active column (with a
-        # 0.5 safety factor so the fp32 true residual lands below tol
-        # despite the lo/hi operator discrepancy), floored at the
-        # attainable per-sweep depth and capped well under 1
-        maxr = jnp.max(jnp.where(active, rnorm, 0.0))
-        itol = jnp.clip(
-            0.5 * jnp.sqrt(tol2) / jnp.where(maxr > 0, maxr, 1.0),
-            inner_tol, 0.3)
+        with obs.scope("vec.update"):
+            active = (rr > tol2) & ~div & ~stag
+            rnorm = jnp.sqrt(rr)
+            safe = jnp.where(active & (rnorm > 0), rnorm, 1.0)
+            # frozen columns get a zero inner RHS: their inner column has
+            # r0 = 0, converges at iteration 0, and block-PCG's freeze
+            # keeps it from perturbing live columns
+            r_hat = jnp.where(active, r / safe, 0.0).astype(lo_dtype)
+            # adaptive inner target: the reduction this sweep still needs
+            # is tol/||r|| per column; take the tightest active column
+            # (with a 0.5 safety factor so the fp32 true residual lands
+            # below tol despite the lo/hi operator discrepancy), floored
+            # at the attainable per-sweep depth and capped well under 1
+            maxr = jnp.max(jnp.where(active, rnorm, 0.0))
+            itol = jnp.clip(
+                0.5 * jnp.sqrt(tol2) / jnp.where(maxr > 0, maxr, 1.0),
+                inner_tol, 0.3)
+            inner_max = jnp.maximum(mi - jnp.max(it), 1)
         res = runner(a_lo, r_hat, precond=precond, tol=itol,
-                     max_iter=jnp.maximum(mi - jnp.max(it), 1), dot=dot,
+                     max_iter=inner_max, dot=inner_dot,
                      stagnation_window=inner_window)
-        d = res.x.astype(jnp.float32) * jnp.where(active, rnorm, 0.0)
-        x_new = x + d
-        r_new = (b32 - a_hi(x_new)).astype(jnp.float32)
+        with obs.scope("vec.update"):
+            d = res.x.astype(jnp.float32) * jnp.where(active, rnorm, 0.0)
+            x_new = x + d
+        ax_new = a_hi(x_new)
+        with obs.scope("vec.update"):
+            return advance(state, active, res, x_new, ax_new)
+
+    def advance(state, active, res, x_new, ax_new):
+        x, r, rr, it, sweeps, div, stag, stall = state
+        r_new = (b32 - ax_new).astype(jnp.float32)
         rr_new = dot(r_new, r_new)
         hurt = active & ~jnp.isfinite(rr_new)
         div = div | hurt
@@ -504,7 +546,9 @@ def refine(a_hi, a_lo, b: jnp.ndarray,
     state = (x, r, rr, jnp.zeros(it_shape, jnp.int32),
              jnp.asarray(0, jnp.int32), jnp.zeros(it_shape, bool),
              jnp.zeros(it_shape, bool), jnp.zeros(it_shape, jnp.int32))
-    x, r, rr, it, _, div, stag, _ = jax.lax.while_loop(cond, body, state)
-    brk = jnp.zeros(it_shape, bool)
-    status = classify(rr, tol2, brk, div, stag)
-    return PCGResult(x, it, jnp.sqrt(rr), r0, brk, status)
+    x, r, rr, it, _, div, stag, _ = jax.lax.while_loop(
+        obs.scoped("vec.update", cond), body, state)
+    with obs.scope("vec.update"):
+        brk = jnp.zeros(it_shape, bool)
+        status = classify(rr, tol2, brk, div, stag)
+        return PCGResult(x, it, jnp.sqrt(rr), r0, brk, status)
